@@ -88,32 +88,9 @@ from __future__ import annotations
 import argparse
 import importlib
 import json
-import os
 import pathlib
 import sys
 import time
-
-
-def _enable_persistent_cache() -> None:
-    """Disk-backed XLA compile cache (the cross-process leg of the
-    translation cache). Kernel timings are unaffected — compile time is
-    measured and reported separately — but re-runs of the suite skip the
-    backend compiles entirely. Opt out with REPRO_JAX_CACHE=0."""
-    if os.environ.get("REPRO_JAX_CACHE", "1") == "0":
-        return
-    cache_dir = os.environ.get(
-        "REPRO_JAX_CACHE_DIR",
-        str(pathlib.Path(__file__).resolve().parents[1]
-            / "experiments" / ".jax_cache"),
-    )
-    try:
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:  # pragma: no cover - older jax without the knobs
-        pass
 
 
 # Modules that register *custom* (non-declarative) workloads on import;
@@ -465,7 +442,11 @@ def main(argv: list[str] | None = None) -> None:
                          "re-invoking replays completed points")
     args = ap.parse_args(argv)
 
-    _enable_persistent_cache()
+    # the cross-process leg of the translation cache: compile time is
+    # reported separately, and re-runs skip the backend compiles
+    from repro.core.staging import enable_persistent_cache
+
+    enable_persistent_cache(str(ROOT / "experiments" / ".jax_cache"))
     from repro import suite
 
     names, import_errors = load_registry()
@@ -522,6 +503,8 @@ def main(argv: list[str] | None = None) -> None:
     failures: list[dict] = []
     # structured --backend skip entries: {workload, backend, reason}
     skipped: list[dict] = []
+    # demotion-ladder steps: {workload, variant, labels, step, stage, error}
+    demotions: list[dict] = []
     module_seconds: dict[str, float] = {}
     # per-workload stage/measure wall-time split from the plan engine
     module_phases: dict[str, dict] = {}
@@ -561,9 +544,11 @@ def main(argv: list[str] | None = None) -> None:
         journal = (str(journal_dir / f"{name}.jsonl")
                    if journal_dir is not None and w.runner is None else None)
         stats: dict = {}
+        demoted: list[dict] = []
         try:
             suite.run_workload(w, quick=not args.full, journal=journal,
-                               backend=exec_backend, executor_stats=stats)
+                               backend=exec_backend, executor_stats=stats,
+                               demotions=demoted)
             module_seconds[name] = round(time.time() - t0, 3)
             print(f"# {name} done in {module_seconds[name]:.1f}s", flush=True)
         except BenchFailure as e:
@@ -588,6 +573,7 @@ def main(argv: list[str] | None = None) -> None:
             failures.append({"workload": name, "stage": "run",
                              "error": type(e).__name__, "message": str(e)})
             print(f"# {name} FAILED: {type(e).__name__}: {e}", flush=True)
+        demotions.extend({"workload": name, **d} for d in demoted)
         if stats:  # declarative workloads: the engine's phase split
             module_phases[name] = {
                 k: (round(v, 3) if isinstance(v, float) else v)
@@ -674,6 +660,7 @@ def main(argv: list[str] | None = None) -> None:
             "module_phases": module_phases,
             "executor": executor,
             "failures": failures,
+            "demotions": demotions,
             "skipped": skipped,
             "translation_cache": GLOBAL_CACHE.stats(),
             "param_path_probe": probe,

@@ -42,16 +42,17 @@ autotuner can share work through the translation cache (see
     kernel issues explicit dynamic slices — on TPU this corresponds to the
     HBM->VMEM manual-DMA style used for halo'd stencils. Blocked-
     ``BlockSpec`` showcase kernels live in ``repro.kernels``. Execution
-    mode is platform-probed once per process (``pallas_platform_mode``):
-    native/compiled where the backend supports ``pl.pallas_call``
-    lowering, ``interpret=True`` otherwise (XLA:CPU).
+    mode is resolved once per process (``pallas_platform_mode``): the
+    interpreter on the CPU backend only, compiled everywhere else. Whole
+    operands must fit the VMEM budget (``PALLAS_VMEM_BUDGET``).
 
 ``lower_pallas_parametric``
     Shape-polymorphic twin of ``lower_pallas``, strided regime only: the
     ``param_strided_window`` specs become pallas *grid* steps over N-D
     ``pl.ds`` windows, with the working-set parameters read from a traced
-    i32 operand — one pallas executable serves a whole working-set
-    ladder, same contract as ``lower_jax_parametric``'s strided path.
+    i32 operand in SMEM — one pallas executable serves a whole working-set
+    ladder, same contract as ``lower_jax_parametric``'s strided path
+    (compiled, the lane windows also start aligned: see its docstring).
 
 ``serial_oracle``
     Pure-numpy execution in generated-code order. The ground truth every
@@ -1505,33 +1506,36 @@ def lower_jax_parametric(
 
 _PALLAS_MODE: dict[str, str] = {}
 
+# The generic emitters below pass no BlockSpecs, so every operand sits
+# whole in VMEM for the kernel's lifetime. Compiled for a described TPU
+# v5e, three f32 operands of 16 MiB each are accepted and three of 17 MiB
+# are refused (RESOURCE_EXHAUSTED in vmem): the budget is 48 MiB.
+PALLAS_VMEM_BUDGET = 48 << 20
+
 
 def pallas_platform_mode() -> str:
-    """Probe-once resolution of how ``pl.pallas_call`` executes here.
+    """How ``pl.pallas_call`` executes on the default jax backend.
 
-    Returns ``"compiled"`` when the default jax backend lowers and runs
-    a trivial pallas kernel natively (TPU/GPU), ``"interpret"`` when
-    only the interpreter is available (XLA:CPU refuses
-    ``interpret=False``). Memoized per process: translation-cache keys,
-    journal fingerprints, and every measurement record embed the result
-    (``extra.pallas_mode``), so artifacts measured under one mode are
-    never replayed as the other's on a different platform.
+    ``"interpret"`` on the CPU backend (XLA:CPU cannot lower Pallas
+    natively), ``"compiled"`` everywhere else. On an accelerator a probe
+    kernel is compiled and run once; when it fails, the compiler's error
+    propagates — a device must never silently time the interpreter.
+    Memoized per process: translation-cache keys, journal fingerprints
+    and every measurement record embed the result (``extra.pallas_mode``).
     """
     mode = _PALLAS_MODE.get("mode")
     if mode is None:
-        def _probe(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1.0
-
-        try:
-            call = pl.pallas_call(
-                _probe,
-                out_shape=jax.ShapeDtypeStruct((8,), jnp.float32),
-                interpret=False,
-            )
-            jax.block_until_ready(jax.jit(call)(jnp.zeros((8,), jnp.float32)))
-            mode = "compiled"
-        except Exception:  # any refusal to lower natively means interpret
+        if jax.default_backend() == "cpu":
             mode = "interpret"
+        else:
+            def _probe(x_ref, o_ref):
+                o_ref[...] = x_ref[...] + 1.0
+
+            call = pl.pallas_call(
+                _probe, out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32))
+            jax.block_until_ready(
+                jax.jit(call)(jnp.zeros((8, 128), jnp.float32)))
+            mode = "compiled"
         _PALLAS_MODE["mode"] = mode
     return mode
 
@@ -1542,9 +1546,25 @@ def _resolve_pallas_mode(mode: str | None) -> str:
     return pallas_platform_mode()
 
 
+def _vmem_preflight(pattern: PatternSpec, shapes: Mapping[str, tuple],
+                    dtypes: Mapping[str, str]) -> None:
+    """Refuse, before Mosaic sees it, a kernel whose whole operands
+    exceed :data:`PALLAS_VMEM_BUDGET`."""
+    need = sum(int(np.prod(shapes[k])) * np.dtype(dtypes[k]).itemsize
+               for k in shapes)
+    if need > PALLAS_VMEM_BUDGET:
+        raise LowerFailure(
+            f"pattern {pattern.name!r}: the pallas emitter holds every "
+            f"operand whole in VMEM; they take {need} bytes, over the "
+            f"{PALLAS_VMEM_BUDGET}-byte budget",
+            context={"backend": "pallas", "reason": "vmem",
+                     "vmem_bytes": need, "budget_bytes": PALLAS_VMEM_BUDGET},
+        )
+
+
 def lower_pallas(
     pattern: PatternSpec, schedule: Schedule, env: Mapping[str, int],
-    *, mode: str | None = None, interpret: bool | None = None,
+    *, mode: str | None = None,
     grid_bands: tuple[str, ...] | None = None,
     plan: NestPlan | None = None,
 ) -> Callable[[dict[str, jnp.ndarray]], dict[str, jnp.ndarray]]:
@@ -1559,19 +1579,16 @@ def lower_pallas(
     (stencil borders) keep their initial values, matching the oracle.
 
     ``mode`` selects ``"compiled"`` (native ``pl.pallas_call`` lowering)
-    or ``"interpret"``; ``None`` auto-resolves via
-    :func:`pallas_platform_mode` so capable platforms run compiled and
-    XLA:CPU falls back to the interpreter. The legacy ``interpret`` bool
-    overrides ``mode`` when given. The built step reports the resolved
-    mode as ``step.pallas_mode``.
+    or ``"interpret"``; ``None`` resolves via :func:`pallas_platform_mode`
+    (the interpreter on XLA:CPU only). The built step reports the
+    resolved mode as ``step.pallas_mode``.
 
-    Refusals (custom kernels, guarded schedules) raise
-    :class:`~repro.core.errors.LowerFailure` with structured context
-    naming the backend and reason, so sweep ``FailureRecord``s classify
-    them instead of carrying a bare exception string.
+    Refusals (custom kernels, guarded schedules, operands over the VMEM
+    budget) raise :class:`~repro.core.errors.LowerFailure` with
+    structured context naming the backend and reason, so sweep
+    ``FailureRecord``s classify them instead of carrying a bare
+    exception string.
     """
-    if interpret is not None:  # legacy kwarg: explicit mode override
-        mode = "interpret" if interpret else "compiled"
     mode = _resolve_pallas_mode(mode)
     if pattern.kernel is not None:
         raise LowerFailure(
@@ -1648,6 +1665,7 @@ def lower_pallas(
     out_pos = space_order.index(out_name)
     shapes = {s.name: s.concrete_shape(env) for s in pattern.spaces}
     dtypes = {s.name: s.dtype for s in pattern.spaces}
+    _vmem_preflight(pattern, shapes, dtypes)
     env_dict = dict(env)
 
     def kernel(*refs):
@@ -1739,8 +1757,16 @@ def lower_pallas_parametric(
     fallback raise :class:`~repro.core.schedule.SymbolicLowerError`, and
     drivers specialize per size instead (pallas has no parametric
     gather emitter).
+
+    Compiled (Mosaic) kernels load lane windows only at offsets that are
+    provably multiples of the lane chunk, so in ``"compiled"`` mode the
+    lane window of grid step ``k`` starts at exactly ``k * C`` (no
+    min-start overlap). That needs ``assume_full`` plus one more caller
+    contract: every rung's lane extent is a multiple of the lane chunk
+    (``Driver`` checks it and specializes ladders that break it).
     """
     from .schedule import SymbolicLowerError
+    from jax.experimental.pallas import tpu as pltpu
 
     if pattern.kernel is not None:
         raise SymbolicLowerError(
@@ -1762,6 +1788,12 @@ def lower_pallas_parametric(
     w = splan.window_band
     wins, Cs = _window_chunks(pnest, splan, cap_env, chunk)
     C = Cs[w]
+    aligned = mode == "compiled"
+    if aligned and not assume_full:
+        raise SymbolicLowerError(
+            "compiled pallas lane windows start aligned and never mask: "
+            "the ladder must tile every rung with full chunks"
+        )
     rest_env = {k: int(v) for k, v in cap_env.items() if k not in params}
     wp = _WindowPlan(pnest, splan, wins, Cs)
     outer_wins = wins[:-1]
@@ -1788,6 +1820,7 @@ def lower_pallas_parametric(
     out_pos = space_order.index(out_name)
     shapes = {s.name: s.concrete_shape(cap_env) for s in pattern.spaces}
     dtypes = {s.name: s.dtype for s in pattern.spaces}
+    _vmem_preflight(pattern, shapes, dtypes)
 
     def kernel(*refs):
         in_refs = {nm: r for nm, r in zip(space_order, refs)}
@@ -1874,7 +1907,8 @@ def lower_pallas_parametric(
             for groups, wacc, s_w in tr:
                 if assume_full:
                     ws = dict(ws0)
-                    ws[w] = jnp.minimum(wsq, win_lo[w])
+                    ws[w] = (pl.multiple_of(wsq, C) if aligned
+                             else jnp.minimum(wsq, win_lo[w]))
                     instance(groups, wacc, ws, ob, None)
                     continue
                 # sign-aware lane anchor, identical to the jax emitter
@@ -1895,9 +1929,15 @@ def lower_pallas_parametric(
         else:
             body()
 
+    # the traced parameters are scalars: they live in SMEM (Mosaic loads
+    # no scalar from VMEM); the arrays stay whole in VMEM
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     call = pl.pallas_call(
         kernel,
         grid=grid,
+        in_specs=[vmem] * len(space_order)
+        + [pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct(shapes[out_name], dtypes[out_name]),
         input_output_aliases={out_pos: 0},
         interpret=(mode == "interpret"),

@@ -218,7 +218,7 @@ class DriverConfig:
     time_budget_s: float | None = None
     # Working-set pre-flight: refuse (CapacityRefused) points whose
     # allocation would exceed this budget. None = process default
-    # (REPRO_CAPACITY_BUDGET env var, else 80% of MemAvailable).
+    # (REPRO_CAPACITY_BUDGET env var, else 80% of the device's memory).
     capacity_budget_bytes: int | None = None
     # Device pinning (the plan engine's device axis): an index into
     # jax.devices(), resolved modulo the device count so a plan written
@@ -389,13 +389,11 @@ class Driver:
         nests) — either way buying the mask-free hot emitter wherever
         the ladder's smallest windows stay big."""
         cfg = self.cfg
-        if cfg.param_path == "gather":
-            if cfg.backend == "pallas":
-                raise SymbolicLowerError(
-                    "the pallas parametric path has no gather regime; "
-                    "ineligible ladders specialize per size"
-                )
-            return "gather", None, False
+        if cfg.param_path == "gather" and cfg.backend == "pallas":
+            raise SymbolicLowerError(
+                "the pallas parametric path has no gather regime; "
+                "ineligible ladders specialize per size"
+            )
         from .codegen import (
             param_strided_in_bounds,
             param_strided_plan,
@@ -404,13 +402,16 @@ class Driver:
 
         pat, sch, _ = self._templated(cap_env)
         pnest = sch.lower_symbolic(pat.domain, ("n",))
+        if cfg.param_path == "gather":
+            return self._gather_regime(pnest, cap_env)
         splan = param_strided_plan(pat, pnest)
         if splan is not None:
             chunk, full = param_strided_window(pnest, splan, list(envs),
                                                cap_env)
             if all(param_strided_in_bounds(pat, pnest, splan, e, cap_env,
                                            chunk)
-                   for e in envs):
+                   for e in envs) and self._pallas_tiles_exactly(
+                       pnest, splan, envs, cap_env, chunk, full):
                 return "strided", chunk, full
         if cfg.param_path == "strided" or cfg.backend == "pallas":
             want = ("param_path='strided'" if cfg.param_path == "strided"
@@ -418,6 +419,39 @@ class Driver:
             raise SymbolicLowerError(
                 f"{want} but the ladder is not strided-eligible under "
                 f"{cfg.template}/{(cfg.schedule or identity()).name}"
+            )
+        return self._gather_regime(pnest, cap_env)
+
+    def _pallas_tiles_exactly(self, pnest, splan, envs, cap_env, chunk,
+                              full: bool) -> bool:
+        """Compiled pallas kernels start every lane window at a multiple
+        of the lane chunk (see ``lower_pallas_parametric``), so such a
+        ladder must be rank-1, mask-free, and tile each rung's lane
+        extent exactly. Other backends and modes have no such contract."""
+        from .codegen import pallas_platform_mode
+
+        if self.cfg.backend != "pallas" or \
+                pallas_platform_mode() != "compiled":
+            return True
+        if not full or not isinstance(chunk, int):
+            return False
+        ext = pnest.band_extents[splan.window_band]
+        return all(ext.eval({**cap_env, **e}) % chunk == 0 for e in envs)
+
+    @staticmethod
+    def _gather_regime(pnest, cap_env: Mapping[str, int]):
+        """The masked gather regime, when its index arrays fit: a ladder
+        whose capacity exceeds the gather emitter's point cap has no
+        shared executable and specializes per size instead."""
+        from .codegen import _GATHER_POINT_CAP
+
+        cap_pts = 1
+        for e in pnest.band_extents:
+            cap_pts *= max(0, e.eval(cap_env))
+        if cap_pts > _GATHER_POINT_CAP:
+            raise SymbolicLowerError(
+                f"the gather regime would stage {cap_pts} capacity points "
+                f"(cap {_GATHER_POINT_CAP}); specialize per size instead"
             )
         return "gather", None, False
 
@@ -448,13 +482,6 @@ class Driver:
             return False
         if not all(pnest.admits(e) for e in envs):
             return False
-        from .codegen import _GATHER_POINT_CAP
-
-        cap_pts = 1
-        for e in pnest.band_extents:
-            cap_pts *= max(0, e.eval(cap_env))
-        if cap_pts > _GATHER_POINT_CAP:
-            return False  # capacity too large to stage; specialize instead
         try:
             # every point's arrays must fit the capacity allocation
             cap_shapes = {s.name: s.concrete_shape(cap_env)

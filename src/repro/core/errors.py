@@ -33,6 +33,7 @@ __all__ = [
     "ResiliencePolicy",
     "classify_failure",
     "available_memory_bytes",
+    "device_memory_bytes",
     "default_capacity_budget",
 ]
 
@@ -207,11 +208,25 @@ def available_memory_bytes() -> int | None:
     return None
 
 
+def device_memory_bytes() -> int | None:
+    """Memory the default device can hold (``memory_stats()
+    ["bytes_limit"]``), or None where the backend reports none."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats()
+    return int(stats["bytes_limit"]) if stats and "bytes_limit" in stats \
+        else None
+
+
 def default_capacity_budget() -> int | None:
     """Capacity budget for the working-set pre-flight, in bytes.
 
-    ``REPRO_CAPACITY_BUDGET`` overrides (empty/0 disables the check);
-    otherwise 80% of MemAvailable; None when neither is knowable."""
+    ``REPRO_CAPACITY_BUDGET`` overrides (empty/0 disables the check).
+    Otherwise 80% of the memory of the device the arrays live on: its
+    ``bytes_limit`` on an accelerator, host ``MemAvailable`` on the CPU
+    backend only. None when the device reports no limit."""
+    import jax
+
     raw = os.environ.get("REPRO_CAPACITY_BUDGET")
     if raw is not None:
         raw = raw.strip()
@@ -221,5 +236,8 @@ def default_capacity_budget() -> int | None:
             return int(raw)
         except ValueError:
             return None
-    avail = available_memory_bytes()
+    if jax.default_backend() == "cpu":
+        avail = available_memory_bytes()
+    else:
+        avail = device_memory_bytes()
     return int(avail * 0.8) if avail else None

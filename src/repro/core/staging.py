@@ -34,15 +34,12 @@ Donation invariant: every *measurement* executable — ``ParamCompiled``
 always, ``Compiled`` when built with ``donate=True`` (what
 ``Driver.prepare`` requests) — donates its array operands, so a call
 consumes its input tuple instead of paying a buffer copy; the ``bind``
-methods thread outputs into subsequent calls, and donated compiles
-carry process-unique module names so jax's persistent cache can never
-hand back a deserialized donated executable (which segfaults on this
-jaxlib — see ``_compile_donated``).
+methods thread outputs into subsequent calls. Donated executables hit
+jax's persistent compile cache across processes like undonated ones.
 """
 from __future__ import annotations
 
 import dataclasses
-import itertools
 import os
 import threading
 import time
@@ -193,35 +190,40 @@ def _install_disk_listener() -> None:
     if _disk_listener_installed:
         return
     _disk_listener_installed = True
-    try:
-        def _on_event(event, **kwargs):
-            key = _DISK_EVENTS.get(event)
-            if key is not None:
-                with _disk_lock:
-                    _disk_counters[key] += 1
 
-        jax.monitoring.register_event_listener(_on_event)
-    except (AttributeError, TypeError):  # pragma: no cover - monitoring
-        # API drift (jax.monitoring moved/renamed): counters stay 0/0.
-        # Deliberately narrow — any *other* fault here is a real bug and
-        # must surface, per the failure-taxonomy policy in core.errors.
-        pass
+    def _on_event(event, **kwargs):
+        key = _DISK_EVENTS.get(event)
+        if key is not None:
+            with _disk_lock:
+                _disk_counters[key] += 1
+
+    jax.monitoring.register_event_listener(_on_event)
+
+
+def enable_persistent_cache(default_dir: str) -> str:
+    """Turn on jax's persistent compile cache for every compile, however
+    fast or small. ``JAX_COMPILATION_CACHE_DIR``, where set, places it
+    (jax reads that variable itself, and no other directory is set);
+    otherwise ``default_dir``, a fixed path — the path is part of the
+    cache's identity, so a moving directory never hits. Returns the
+    directory in use."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = default_dir
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir
 
 
 def disk_cache_stats() -> dict:
     """jax persistent-cache counters for this process (0/0 when the disk
     cache is disabled — events never fire)."""
-    try:
-        from jax._src import compilation_cache as _cc
+    from jax._src import compilation_cache as _cc
 
-        enabled = bool(_cc.is_persistent_cache_enabled())
-    except (ImportError, AttributeError):  # pragma: no cover - private
-        # jax API drift; narrow so real faults are not misreported as
-        # "disk cache disabled"
-        enabled = False
     with _disk_lock:
         return {
-            "enabled": enabled,
+            "enabled": bool(_cc.is_persistent_cache_enabled()),
             "hits": _disk_counters["hits"],
             "misses": _disk_counters["misses"],
         }
@@ -537,28 +539,11 @@ class ParamCompiled:
         return ca
 
 
-# Donated executables and jax's persistent compilation cache do not mix
-# on this jaxlib: a donated executable *deserialized* from the disk
-# cache segfaults at call time. The cache cannot be suspended per
-# compile either — jax latches its use-the-cache decision once per
-# process (``compilation_cache.is_cache_used``), so toggling the config
-# around one compile either does nothing or kills the cache for every
-# compile that follows (observed: the smoke suite's disk traffic
-# dropped to zero). Instead, each donated compile — parametric AND the
-# donated specialized measurement executables — gets a process-unique
-# module name: the name is part of the cache key, so a donated
-# executable can never be *retrieved* from disk (no deserialization, no
-# segfault) while undonated compiles keep their cross-run cache hits.
-# Cost: donated compiles write never-reused entries (one per distinct
-# measurement executable); the in-process TranslationCache still
-# deduplicates them within a run.
-_donated_serial = itertools.count()
-
-
 def _compile_donated(fn, *aval_groups):
-    fn.__name__ = (
-        f"{fn.__name__}_donated_{os.getpid()}_{next(_donated_serial)}"
-    )
+    """AOT-compile ``fn`` with its array tuple donated. Donated
+    executables share jax's persistent cache like any other: the module
+    name is the function's own, so a cold process finds the executable a
+    previous one compiled."""
     return jax.jit(fn, donate_argnums=(0,)).lower(*aval_groups).compile()
 
 

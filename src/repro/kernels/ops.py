@@ -27,7 +27,7 @@ __all__ = [
 
 @partial(jax.jit, static_argnames=("scalar", "block", "interpret"))
 def triad(b: jnp.ndarray, c: jnp.ndarray, *, scalar: float = 3.0,
-          block: int = 4096, interpret: bool = True) -> jnp.ndarray:
+          block: int = 4096, interpret: bool | None = None) -> jnp.ndarray:
     return _stream.stream(
         lambda bb, cc: bb + scalar * cc, b, c, block=block, interpret=interpret
     )
@@ -35,7 +35,7 @@ def triad(b: jnp.ndarray, c: jnp.ndarray, *, scalar: float = 3.0,
 
 @partial(jax.jit, static_argnames=("scalar", "block", "interpret"))
 def nstream(streams: tuple[jnp.ndarray, ...], *, scalar: float = 3.0,
-            block: int = 4096, interpret: bool = True) -> jnp.ndarray:
+            block: int = 4096, interpret: bool | None = None) -> jnp.ndarray:
     """A = scalar*S0 + S1 + ... (k concurrent read streams, paper Fig. 7)."""
     def combine(*vals):
         acc = vals[0] * scalar
@@ -49,7 +49,7 @@ def nstream(streams: tuple[jnp.ndarray, ...], *, scalar: float = 3.0,
 @partial(jax.jit, static_argnames=("scalar", "factor", "block", "interpret"))
 def triad_interleaved(b: jnp.ndarray, c: jnp.ndarray, *, scalar: float = 3.0,
                       factor: int = 2, block: int = 1024,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     return _stream.interleaved(
         lambda bb, cc: bb + scalar * cc, b, c,
         factor=factor, block=block, interpret=interpret,
@@ -58,13 +58,13 @@ def triad_interleaved(b: jnp.ndarray, c: jnp.ndarray, *, scalar: float = 3.0,
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
 def jacobi1d(b: jnp.ndarray, *, block: int = 1024,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret: bool | None = None) -> jnp.ndarray:
     return _stencil.jacobi1d_blocked(b, block=block, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block", "points", "interpret"))
 def jacobi2d(b: jnp.ndarray, *, block: tuple[int, int] = (128, 128),
-             points: int = 5, interpret: bool = True) -> jnp.ndarray:
+             points: int = 5, interpret: bool | None = None) -> jnp.ndarray:
     return _stencil.jacobi2d_blocked(
         b, block=block, points=points, interpret=interpret
     )
@@ -72,11 +72,11 @@ def jacobi2d(b: jnp.ndarray, *, block: tuple[int, int] = (128, 128),
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
 def jacobi3d(b: jnp.ndarray, *, block: tuple[int, int, int] = (8, 8, 128),
-             interpret: bool = True) -> jnp.ndarray:
+             interpret: bool | None = None) -> jnp.ndarray:
     return _stencil.jacobi3d_blocked(b, block=block, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
 def jacobi3d_streaming(b: jnp.ndarray, *, block: tuple[int, int] = (8, 128),
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     return _stencil.jacobi3d_streaming(b, block=block, interpret=interpret)

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .stream import resolve_interpret
+
 __all__ = [
     "jacobi1d_blocked",
     "jacobi2d_blocked",
@@ -49,7 +51,7 @@ def _div(a: int, b: int, what: str) -> int:
 
 
 def jacobi1d_blocked(b: jnp.ndarray, *, block: int = 1024,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool | None = None) -> jnp.ndarray:
     """A[i] = (B[i-1]+B[i]+B[i+1])/3 on 1 <= i < n-1; A keeps B's borders.
 
     Interior (n-2) must be divisible by ``block``. Output is blocked;
@@ -72,13 +74,13 @@ def jacobi1d_blocked(b: jnp.ndarray, *, block: int = 1024,
         in_specs=[pl.BlockSpec(b.shape, lambda i: (0,))],  # whole array
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((interior,), b.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(b)
     return b.at[1:-1].set(interior_out)
 
 
 def jacobi2d_blocked(b: jnp.ndarray, *, block: tuple[int, int] = (128, 128),
-                     points: int = 5, interpret: bool = True) -> jnp.ndarray:
+                     points: int = 5, interpret: bool | None = None) -> jnp.ndarray:
     """5-pt star or 9-pt box Jacobi 2D with a 2D grid of output tiles."""
     n0, n1 = b.shape
     bi = min(block[0], n0 - 2)
@@ -110,13 +112,13 @@ def jacobi2d_blocked(b: jnp.ndarray, *, block: tuple[int, int] = (128, 128),
         in_specs=[pl.BlockSpec(b.shape, lambda i, j: (0, 0))],
         out_specs=pl.BlockSpec((bi, bj), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n0 - 2, n1 - 2), b.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(b)
     return b.at[1:-1, 1:-1].set(interior)
 
 
 def jacobi3d_blocked(b: jnp.ndarray, *, block: tuple[int, int, int] = (8, 8, 128),
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool | None = None) -> jnp.ndarray:
     """7-pt Jacobi 3D, xyz tiling (paper Listing 9): 3D grid of tiles.
 
     Every tile re-fetches a (bi+2, bj+2, bk+2) halo'd window — the halo
@@ -150,13 +152,13 @@ def jacobi3d_blocked(b: jnp.ndarray, *, block: tuple[int, int, int] = (8, 8, 128
         in_specs=[pl.BlockSpec(b.shape, lambda i, j, k: (0, 0, 0))],
         out_specs=pl.BlockSpec((bi, bj, bk), lambda i, j, k: (i, j, k)),
         out_shape=jax.ShapeDtypeStruct((n0 - 2, n1 - 2, n2 - 2), b.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(b)
     return b.at[1:-1, 1:-1, 1:-1].set(interior)
 
 
 def jacobi3d_streaming(b: jnp.ndarray, *, block: tuple[int, int] = (8, 128),
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """7-pt Jacobi 3D, partial (j,k) blocking with the i dim *streamed*.
 
     The TPU-native version of Rivera-Tseng partial blocking: a 2D grid of
@@ -202,6 +204,6 @@ def jacobi3d_streaming(b: jnp.ndarray, *, block: tuple[int, int] = (8, 128),
         in_specs=[pl.BlockSpec(b.shape, lambda j, k: (0, 0, 0))],
         out_specs=pl.BlockSpec((n0 - 2, bj, bk), lambda j, k: (0, j, k)),
         out_shape=jax.ShapeDtypeStruct((n0 - 2, n1 - 2, n2 - 2), b.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(b)
     return b.at[1:-1, 1:-1, 1:-1].set(interior)

@@ -3,8 +3,10 @@
 These are the BlockSpec-tiled showcase versions of the patterns the
 generic ``repro.core.codegen`` backend lowers in manual-DMA style. Block
 shapes default to multiples of the v5e native tile (8x128 f32 = 1024
-elements) so the MXU/VPU sees hardware-aligned operands; ``interpret=True``
-executes the same kernels on CPU for validation.
+elements) so the MXU/VPU sees hardware-aligned operands. ``interpret=None``
+(every kernel's default) resolves through
+``codegen.pallas_platform_mode()``: compiled on an accelerator, the
+interpreter on XLA:CPU only.
 
 Kernels:
 
@@ -29,24 +31,30 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["stream", "interleaved", "NATIVE_BLOCK"]
+from repro.core.codegen import pallas_platform_mode
+
+__all__ = ["stream", "interleaved", "NATIVE_BLOCK", "resolve_interpret"]
 
 NATIVE_BLOCK = 8 * 128  # one f32 native tile, flattened
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """An explicit flag wins; ``None`` follows the platform."""
+    if interpret is None:
+        return pallas_platform_mode() == "interpret"
+    return interpret
 
 
 def _check(n: int, block: int) -> None:
     if n % block != 0:
         raise ValueError(f"block {block} must divide n {n}")
-    if block % NATIVE_BLOCK != 0:
-        # allowed (interpret mode), but the TPU target wants tile multiples
-        pass
 
 
 def stream(
     combine: Callable[..., jnp.ndarray],
     *streams: jnp.ndarray,
     block: int = 4 * NATIVE_BLOCK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """A[i] = combine(streams...[i]) with 1D BlockSpec tiling.
 
@@ -71,7 +79,7 @@ def stream(
         in_specs=[spec] * len(streams),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n,), streams[0].dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*streams)
 
 
@@ -80,7 +88,7 @@ def interleaved(
     *streams: jnp.ndarray,
     factor: int = 2,
     block: int = NATIVE_BLOCK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Interleaved-by-``factor`` stream: each grid step touches ``factor``
     disjoint segments of every operand at once (paper Listing 7).
@@ -109,6 +117,6 @@ def interleaved(
         in_specs=[spec] * len(streams),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((factor, seg), streams[0].dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*[s.reshape(factor, seg) for s in streams])
     return out2d.reshape(n)

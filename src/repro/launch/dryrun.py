@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds the production mesh (16,16) or (2,16,16), the
@@ -24,6 +21,7 @@ stage. Usage:
 import argparse
 import dataclasses
 import json
+import os
 import pathlib
 import time
 import traceback
@@ -195,6 +193,9 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool) -> dict:
 
 
 def main() -> None:
+    # the production meshes need 512 host devices; set before the first
+    # jax call initializes the CPU backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

@@ -27,21 +27,6 @@ import numpy as np
 
 from jax.sharding import PartitionSpec as P
 
-try:  # top-level export landed after 0.4.x
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-# independently of the export move; probe the actual signature
-import inspect as _inspect
-
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
-
 from .layers import DEFAULT_DTYPE, init_linear
 
 __all__ = ["moe_init", "moe_apply", "Parallelism"]
@@ -221,11 +206,11 @@ def moe_apply(p: dict, x: jnp.ndarray, moe, *, par: Parallelism | None,
                 out = _allgather(out, fsdp, axis=1)  # (T, d)
             return out
 
-        out = _shard_map(
+        out = jax.shard_map(
             shard_fn, mesh=par.mesh,
             in_specs=(xs, xs, ws, ws, wos),
             out_specs=xs,
-            **{_SHARD_MAP_CHECK_KW: False},
+            check_vma=False,
         )(x2d, gates, p["w_gate_e"], p["w_in_e"], p["w_out_e"])
 
     y = out.astype(x.dtype).reshape(B, S, d)
@@ -237,16 +222,10 @@ def moe_apply(p: dict, x: jnp.ndarray, moe, *, par: Parallelism | None,
     return y, aux
 
 
-def _axis_size(a: str) -> int:
-    if hasattr(jax.lax, "axis_size"):  # jax >= 0.5
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)  # 0.4.x: count participants
-
-
 def _ep_offset(ep_axes: tuple[str, ...], e_local: int):
     idx = 0
     for a in ep_axes:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx * e_local
 
 

@@ -63,11 +63,10 @@ def expected_wire_bytes(op: str, shard_elems: int, k: int,
 
 
 def _sharded_ops(mesh):
-    """jit-wrapped shard_map bodies per op. ``check_rep=False`` is
+    """jit-wrapped shard_map bodies per op. ``check_vma=False`` is
     required: shard_map cannot statically infer that the collective
     results are replicated, and without it tracing raises."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def all_gather(x):
@@ -77,24 +76,27 @@ def _sharded_ops(mesh):
         return jax.lax.psum(x, "device")
 
     kw = dict(mesh=mesh, in_specs=P("device"), out_specs=P(None),
-              check_rep=False)
+              check_vma=False)
     return {
-        "all_gather": jax.jit(shard_map(all_gather, **kw)),
-        "all_reduce": jax.jit(shard_map(all_reduce, **kw)),
+        "all_gather": jax.jit(jax.shard_map(all_gather, **kw)),
+        "all_reduce": jax.jit(jax.shard_map(all_reduce, **kw)),
     }
 
 
-def measure_collectives(quick: bool = True, *, mesh=None,
-                        reps: int = 3) -> list[dict]:
+def measure_collectives(quick: bool = True, *, mesh=None, reps: int = 3,
+                        sizes: "tuple[int, ...] | None" = None) -> list[dict]:
     """Run the ladder; one dict per (op, shard size) point.
 
     Keys: ``op``, ``devices``, ``shard_elems``, ``wire_bytes`` (ring
     accounting, per device), ``hlo_bytes`` (analyze_collectives, per
-    device), ``agreement`` (hlo / ring), ``seconds``, ``gbs``
-    (aggregate wire GB/s). Empty on a <2-device mesh — there is no wire
-    to measure.
+    device), ``agreement`` (hlo / ring), ``values_ok`` (the gathered or
+    reduced result against numpy), ``seconds``, ``gbs`` (aggregate wire
+    GB/s). ``sizes`` overrides the per-device shard ladder of
+    :func:`collective_sizes`. Empty on a <2-device mesh — there is no
+    wire to measure.
     """
     import jax.numpy as jnp
+    import numpy as np
 
     from repro.core.measure import time_fn
     from repro.launch.hlo_analysis import analyze_collectives
@@ -107,12 +109,20 @@ def measure_collectives(quick: bool = True, *, mesh=None,
     ops = _sharded_ops(mesh)
     out: list[dict] = []
     for op in COLLECTIVE_OPS:
-        for s in collective_sizes(quick):
+        for s in (sizes or collective_sizes(quick)):
             x = jnp.linspace(0.0, 1.0, k * s, dtype=jnp.float32)
             compiled = ops[op].lower(x).compile()
             stats = analyze_collectives(compiled.as_text())
             hlo_bytes = stats.bytes_by_kind.get(HLO_KIND[op], 0.0)
             wire = expected_wire_bytes(op, s, k)
+            got = np.asarray(compiled(x))
+            host = np.asarray(x)
+            if op == "all_gather":
+                values_ok = bool(np.array_equal(got, host))
+            else:  # the reduction order is the collective's own
+                values_ok = bool(np.allclose(
+                    got, host.reshape(k, s).sum(axis=0, dtype=np.float64),
+                    rtol=1e-6, atol=1e-6))
             t = time_fn(compiled, x, reps=reps, warmup=1)
             out.append({
                 "op": op,
@@ -121,6 +131,7 @@ def measure_collectives(quick: bool = True, *, mesh=None,
                 "wire_bytes": wire,
                 "hlo_bytes": hlo_bytes,
                 "agreement": hlo_bytes / wire if wire else float("nan"),
+                "values_ok": values_ok,
                 "seconds": t.seconds,
                 "gbs": k * wire / t.seconds / 1e9,
             })
@@ -146,7 +157,7 @@ def collective_runner(quick: bool = True) -> list[str]:
     rows = measure_collectives(quick)
     lines, bad = [], 0
     for r in rows:
-        ok = abs(r["agreement"] - 1.0) <= 0.10
+        ok = abs(r["agreement"] - 1.0) <= 0.10 and r["values_ok"]
         bad += 0 if ok else 1
         lines.append(
             f"collective/{r['op']}/k{r['devices']}/s{r['shard_elems']},"
@@ -156,5 +167,6 @@ def collective_runner(quick: bool = True) -> list[str]:
         )
     if bad:
         lines.append(
-            f"# collective ring-vs-hlo byte mismatch on {bad} point(s)")
+            f"# collective ring-vs-hlo byte or value mismatch on {bad} "
+            "point(s)")
     return emit(lines)

@@ -10,6 +10,8 @@ line.
 """
 from __future__ import annotations
 
+import dataclasses
+
 from repro.core import GLOBAL_CACHE, Record, TranslationCache
 from repro.core.errors import ResiliencePolicy
 
@@ -90,7 +92,8 @@ def run_workload(w: Workload, quick: bool = True, *,
                  cache: TranslationCache | None = None,
                  journal: "RunJournal | str | None" = None,
                  backend: "ExecutionBackend | None" = None,
-                 executor_stats: "dict | None" = None) -> list[str]:
+                 executor_stats: "dict | None" = None,
+                 demotions: "list | None" = None) -> list[str]:
     """Execute one workload (declarative or custom) and emit its CSV.
 
     Fault-isolated: a failing plan point is demoted/retried by the
@@ -104,7 +107,9 @@ def run_workload(w: Workload, quick: bool = True, *,
     ``backend`` picks the plan engine's execution backend (custom-runner
     workloads ignore it — they own their execution). When the caller
     passes an ``executor_stats`` dict, the report's per-phase executor
-    accounting is copied into it (the ledger's stage/measure split).
+    accounting is copied into it (the ledger's stage/measure split); a
+    ``demotions`` list receives every demotion-ladder step taken, as a
+    dict (a demoted point ran under another config than declared).
     """
     if w.runner is not None:
         return list(w.runner(quick))
@@ -134,6 +139,8 @@ def run_workload(w: Workload, quick: bool = True, *,
     for d in report.demotions:
         print(f"# {w.name} demoted [{d.step}] after {d.stage}:{d.error} "
               f"({', '.join(d.labels)})", flush=True)
+        if demotions is not None:
+            demotions.append(dataclasses.asdict(d))
     for f in report.failures:
         print(f"# {w.name} FAILED {f.variant}/{f.label}: "
               f"{f.stage}:{f.error}: {f.message}", flush=True)

@@ -662,4 +662,5 @@ def test_collective_ladder_agreement_on_forced_mesh(tmp_path):
     assert all(r["devices"] == 8 for r in rows)
     for r in rows:
         assert abs(r["agreement"] - 1.0) <= 0.10, r
+        assert r["values_ok"], r
         assert r["gbs"] > 0 and r["seconds"] > 0

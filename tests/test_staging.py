@@ -417,3 +417,53 @@ def test_disk_counter_listener_updates_are_locked():
     assert after["hits"] - before["hits"] == 8000
     with staging_mod._disk_lock:
         staging_mod._disk_counters["hits"] = before["hits"]
+
+
+_DONATED_FROM_DISK = """
+import json
+import jax.numpy as jnp
+import numpy as np
+from repro.core import Driver, DriverConfig, triad
+from repro.core.staging import disk_cache_stats, enable_persistent_cache
+
+enable_persistent_cache("unused-when-the-variable-is-set")
+d = Driver(lambda env: triad(), DriverConfig(
+    template="independent", programs=2, parametric="auto", ntimes=2,
+    reps=1))
+ladder = [1024, 4096]
+d.run(ladder)
+p = d.prepare(ladder, parallel=False)[-1]
+arrays = p.lowered.pattern.allocate(p.lowered.env)
+out = p.executable()(tuple(jnp.asarray(arrays[k]) for k in p.compiled.names))
+print(json.dumps({"disk": disk_cache_stats(), "donated": p.parametric,
+                  "A": float(np.asarray(out[0]).min()),
+                  "A_max": float(np.asarray(out[0]).max())}))
+"""
+
+
+def test_donated_executables_load_back_from_the_disk_cache(tmp_path):
+    """A second cold process finds the donated parametric executable in
+    jax's persistent cache (placed by JAX_COMPILATION_CACHE_DIR) and
+    runs it to the right answer: no process-unique module names."""
+    import json
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    runs = []
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", _DONATED_FROM_DISK],
+                             env=env, capture_output=True, text=True,
+                             timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    first, second = runs
+    assert first["disk"]["enabled"] and first["disk"]["hits"] == 0
+    assert second["disk"]["hits"] > 0 and second["disk"]["misses"] == 0
+    for r in runs:
+        assert r["donated"] and r["A"] == r["A_max"] == 3.0 + 3.0 * 4.0
+    assert any((tmp_path / "cache").iterdir())
