@@ -118,6 +118,13 @@ __all__ = [
 # constants), so the cap only bounds runtime index-array memory.
 _GATHER_POINT_CAP = 1 << 26
 
+# Named scopes of the XLA emitters' read, combine and write: stable names
+# in each compiled op's ``op_name`` metadata, whatever names XLA gives the
+# instructions (metadata only: the ops are the same with or without them).
+SCOPE_READ = "repro.window.read"
+SCOPE_COMBINE = "repro.window.combine"
+SCOPE_WRITE = "repro.window.write"
+
 # Lane-block size of the parametric (shape-polymorphic) path: points are
 # executed in fixed-shape chunks under a dynamic trip count, so the work
 # a call performs scales with the runtime working set, not the capacity.
@@ -448,22 +455,26 @@ def lower_jax(
                     w_sl.append(sl)
                     w_bands.append(b)
                 vals = []
-                for acc, rr in zip(stmt.reads, racc):
-                    sls, bands_order = [], []
-                    for row, const in rr:
-                        sl, b = _slice_for(row, const, nest.band_extents)
-                        sls.append(sl)
-                        bands_order.append(b)
-                    v = arrays[acc.space][tuple(sls)]
-                    perm = _axis_perm(bands_order, w_bands)
-                    if perm is not None:
-                        v = jnp.transpose(v, perm)
-                    vals.append(v)
-                res = stmt.combine(vals, dict(env))
+                with jax.named_scope(SCOPE_READ):
+                    for acc, rr in zip(stmt.reads, racc):
+                        sls, bands_order = [], []
+                        for row, const in rr:
+                            sl, b = _slice_for(row, const,
+                                               nest.band_extents)
+                            sls.append(sl)
+                            bands_order.append(b)
+                        v = arrays[acc.space][tuple(sls)]
+                        perm = _axis_perm(bands_order, w_bands)
+                        if perm is not None:
+                            v = jnp.transpose(v, perm)
+                        vals.append(v)
+                with jax.named_scope(SCOPE_COMBINE):
+                    res = stmt.combine(vals, dict(env))
                 tgt = arrays[stmt.write.space]
-                arrays[stmt.write.space] = tgt.at[tuple(w_sl)].set(
-                    jnp.asarray(res).astype(tgt.dtype)
-                )
+                with jax.named_scope(SCOPE_WRITE):
+                    arrays[stmt.write.space] = tgt.at[tuple(w_sl)].set(
+                        jnp.asarray(res).astype(tgt.dtype)
+                    )
             return arrays
 
         return step
@@ -521,18 +532,22 @@ def lower_jax(
                     it = lin(inst.A[d], inst.c[d])
                     mask &= (it >= nest.domain_lo[d]) & (it < nest.domain_hi[d])
             # OOB reads clamp (jit default); their lanes are dropped on write
-            vals = [
-                arrays[acc.space][tuple(lin(row, const) for row, const in rows)]
-                for acc, rows in zip(stmt.reads, racc)
-            ]
-            res = stmt.combine(vals, dict(env))
+            with jax.named_scope(SCOPE_READ):
+                vals = [
+                    arrays[acc.space][tuple(lin(row, const)
+                                            for row, const in rows)]
+                    for acc, rows in zip(stmt.reads, racc)
+                ]
+            with jax.named_scope(SCOPE_COMBINE):
+                res = stmt.combine(vals, dict(env))
             tgt = arrays[stmt.write.space]
-            widx = tuple(lin(row, const) for row, const in wacc)
-            if mask is not None:
-                widx = tuple(jnp.where(mask, ix, -1) for ix in widx)
-            arrays[stmt.write.space] = tgt.at[widx].set(
-                jnp.asarray(res).astype(tgt.dtype), mode="drop"
-            )
+            with jax.named_scope(SCOPE_WRITE):
+                widx = tuple(lin(row, const) for row, const in wacc)
+                if mask is not None:
+                    widx = tuple(jnp.where(mask, ix, -1) for ix in widx)
+                arrays[stmt.write.space] = tgt.at[widx].set(
+                    jnp.asarray(res).astype(tgt.dtype), mode="drop"
+                )
         return arrays
 
     return step
@@ -1150,31 +1165,37 @@ def _lower_param_strided(pattern: PatternSpec, pnest: ParamNest,
             wstarts, wsizes, wsel, waxes = wp.spec(wacc, ws, ob)
             fit = wp.align(waxes)
             vals: list = [None] * len(stmt.reads)
-            for space, hull_rows, spans, members in groups:
-                starts, sizes, sel, raxes = wp.spec(hull_rows, ws, ob)
-                hsizes = [s + sp for s, sp in zip(sizes, spans)]
-                hull = jax.lax.dynamic_slice(arrs[space], starts, hsizes)
-                for ridx, offs in members:
-                    sub = hull[tuple(
-                        slice(o, o + s) for o, s in zip(offs, sizes)
-                    )]
-                    vals[ridx] = fit(jnp, sub[sel], raxes)
-            res = stmt.combine(vals, cenv)
+            with jax.named_scope(SCOPE_READ):
+                for space, hull_rows, spans, members in groups:
+                    starts, sizes, sel, raxes = wp.spec(hull_rows, ws, ob)
+                    hsizes = [s + sp for s, sp in zip(sizes, spans)]
+                    hull = jax.lax.dynamic_slice(arrs[space], starts,
+                                                 hsizes)
+                    for ridx, offs in members:
+                        sub = hull[tuple(
+                            slice(o, o + s) for o, s in zip(offs, sizes)
+                        )]
+                        vals[ridx] = fit(jnp, sub[sel], raxes)
             tgt = arrs[stmt.write.space]
-            lanes = tuple(
-                wp.lane_extent(b) if b is not None else 1 for b in waxes
-            )
-            res = jnp.broadcast_to(jnp.asarray(res).astype(tgt.dtype), lanes)
-            if valid is None and all(cf == 1 for b, cf, _ in wacc if b >= 0):
-                return jax.lax.dynamic_update_slice(tgt, res, wstarts)
-            # strided / reversed / masked write: blend into the window
-            # so gap elements and invalid lanes stay untouched
-            win = jax.lax.dynamic_slice(tgt, wstarts, wsizes)
-            if valid is not None:
-                vshape = tuple(C if b == w else 1 for b in waxes)
-                res = jnp.where(valid.reshape(vshape), res, win[wsel])
-            win = win.at[wsel].set(res)
-            return jax.lax.dynamic_update_slice(tgt, win, wstarts)
+            with jax.named_scope(SCOPE_COMBINE):
+                res = stmt.combine(vals, cenv)
+                lanes = tuple(
+                    wp.lane_extent(b) if b is not None else 1 for b in waxes
+                )
+                res = jnp.broadcast_to(jnp.asarray(res).astype(tgt.dtype),
+                                       lanes)
+            with jax.named_scope(SCOPE_WRITE):
+                if valid is None and all(cf == 1 for b, cf, _ in wacc
+                                         if b >= 0):
+                    return jax.lax.dynamic_update_slice(tgt, res, wstarts)
+                # strided / reversed / masked write: blend into the
+                # window so gap elements and invalid lanes stay untouched
+                win = jax.lax.dynamic_slice(tgt, wstarts, wsizes)
+                if valid is not None:
+                    vshape = tuple(C if b == w else 1 for b in waxes)
+                    res = jnp.where(valid.reshape(vshape), res, win[wsel])
+                win = win.at[wsel].set(res)
+                return jax.lax.dynamic_update_slice(tgt, win, wstarts)
 
         def body(ci, arrs):
             arrs = dict(arrs)

@@ -45,6 +45,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import spans
 from .codegen import serial_oracle
 from .domain import Affine, Dim, IterDomain
 from .errors import (
@@ -602,9 +603,21 @@ class Driver:
         the rest are cache hits, and ``run`` passes each point's ``n`` at
         call time. Specialized path: lower serially (cheap, GIL-bound),
         then AOT-compile the points concurrently (XLA releases the GIL).
+
+        Runs inside a ``repro.prepare`` span (``path`` = parametric or
+        specialized, ``points``), its regime resolution inside
+        ``repro.resolve``.
         """
-        cfg = self.cfg
         envs = self._point_envs(working_sets, env_extra)
+        with spans.span("repro.prepare", points=len(envs)) as sp:
+            preps = self._prepare(envs, working_sets, parallel)
+            sp.attrs["path"] = ("parametric" if preps and preps[0].parametric
+                                else "specialized")
+        return preps
+
+    def _prepare(self, envs: list[dict], working_sets,
+                 parallel: bool) -> list[Prepared]:
+        cfg = self.cfg
         # "auto" only shares when there is a ladder to share across: a
         # single-point run gains nothing from the parametric regime and
         # would pay its chunked-gather overhead for free, so it keeps the
@@ -615,15 +628,16 @@ class Driver:
         if want_parametric:
             cap_env = max(envs, key=lambda e: e["n"])
             resolved = None
-            if self._parametric_viable(envs, cap_env):
-                try:
-                    # single resolution pass: a forced-strided ladder
-                    # that is not window-safe raises here and falls
-                    # through to specialization (or re-raises under
-                    # parametric=True)
-                    resolved = self._resolve_param_path(envs, cap_env)
-                except SymbolicLowerError:
-                    resolved = None
+            with spans.span("repro.resolve"):
+                if self._parametric_viable(envs, cap_env):
+                    try:
+                        # single resolution pass: a forced-strided ladder
+                        # that is not window-safe raises here and falls
+                        # through to specialization (or re-raises under
+                        # parametric=True)
+                        resolved = self._resolve_param_path(envs, cap_env)
+                    except SymbolicLowerError:
+                        resolved = None
             if resolved is not None:
                 path, chunk, full = resolved
                 preps = []
@@ -712,6 +726,7 @@ class Driver:
 
     # -- validation (the <kernel>_val.in stage) ------------------------------
 
+    @spans.spanned("repro.validate")
     def validate(self, env: Mapping[str, int] | None = None) -> None:
         """Replay the run schedule against the numpy oracle.
 
@@ -742,6 +757,7 @@ class Driver:
 
     # -- measurement ---------------------------------------------------------
 
+    @spans.spanned("repro.measure")
     def measure_point(self, p: Prepared) -> Record:
         """Measure ONE staged point — the per-point isolation unit the
         plan engine wraps (a fault here fails this point, not the
@@ -842,6 +858,7 @@ class Driver:
         return [self.measure_point(p)
                 for p in self.prepare(working_sets, env_extra)]
 
+    @spans.spanned("repro.validate")
     def validate_parametric(self,
                             working_sets: "Sequence[int | Mapping[str, int]]",
                             env_extra: Mapping[str, int] | None = None,

@@ -42,7 +42,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
-import time
 from collections import OrderedDict
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Mapping, Sequence
@@ -51,6 +50,7 @@ import numpy as np
 
 import jax
 
+from . import spans
 from .pattern import PatternSpec
 from .schedule import Schedule
 
@@ -282,11 +282,9 @@ class Lowered:
         if self.key is not None:
             key = ("exec", self.key, int(ntimes), bool(sync_every_rep),
                    bool(donate))
-        builder = lambda: _build_compiled(self, ntimes, sync_every_rep,
-                                          donate)
-        if cache is None or key is None:
-            return builder()
-        out, hit = cache._compiled_get_or_build(key, builder)
+        out, hit = _compile_span(
+            cache, key,
+            lambda: _build_compiled(self, ntimes, sync_every_rep, donate))
         # per-caller view: never mutate the shared cached object (racy
         # under precompile threads and wrong for duplicate points)
         return dataclasses.replace(out, from_cache=hit) if hit else out
@@ -325,9 +323,15 @@ class Compiled:
         :meth:`ParamCompiled.bind`: repeated calls (the timing loop)
         feed each call's output tuple into the next, so the caller's
         seed tuple is only consumed once — and a *different* tuple
-        passed later raises instead of being silently ignored."""
+        passed later raises instead of being silently ignored.
+
+        With spans on (``spans.enable()`` before this call) each call
+        runs inside a ``repro.dispatch`` span; with them off the loop
+        gets the plain callable, decided here and not per call."""
+        run = spans.wrap(self.run, "repro.dispatch",
+                         n=self.lowered.env.get("n"))
         if not self.donated:
-            return self.run
+            return run
         state: dict = {}
 
         def fn(tup):
@@ -341,7 +345,7 @@ class Compiled:
                 tup = state["tup"]
             else:
                 state["seed"] = tup
-            out = self.run(tup)
+            out = run(tup)
             state["tup"] = out
             return out
 
@@ -367,7 +371,6 @@ def _build_compiled(lowered: Lowered, ntimes: int,
     avals = lowered.avals()
     compile_one = (_compile_donated if donate
                    else lambda fn, *a: jax.jit(fn).lower(*a).compile())
-    t0 = time.perf_counter()
     if sync_every_rep:
         exe = compile_one(step_t, avals)
 
@@ -382,11 +385,10 @@ def _build_compiled(lowered: Lowered, ntimes: int,
 
         exe = compile_one(fused, avals)
         run = exe
-    compile_seconds = time.perf_counter() - t0
     return Compiled(
         lowered=lowered, names=names, run=run, executable=exe,
         ntimes=ntimes, sync_every_rep=sync_every_rep,
-        compile_seconds=compile_seconds, donated=donate,
+        compile_seconds=0.0, donated=donate,
     )
 
 
@@ -455,10 +457,9 @@ class ParamLowered:
         key = None
         if self.key is not None:
             key = ("pexec", self.key, int(ntimes), bool(sync_every_rep))
-        builder = lambda: _build_param_compiled(self, ntimes, sync_every_rep)
-        if cache is None or key is None:
-            return builder()
-        out, hit = cache._compiled_get_or_build(key, builder)
+        out, hit = _compile_span(
+            cache, key,
+            lambda: _build_param_compiled(self, ntimes, sync_every_rep))
         return dataclasses.replace(out, from_cache=hit) if hit else out
 
 
@@ -511,8 +512,13 @@ class ParamCompiled:
         *different* tuple passed to a later call would be silently
         ignored. That is a measurement-loop contract (the loop re-passes
         the same seed tuple every rep), so passing anything else raises
-        instead of computing on stale state."""
+        instead of computing on stale state.
+
+        With spans on (``spans.enable()`` before this call) each call
+        runs inside a ``repro.dispatch`` span; with them off the loop
+        gets the plain callable, decided here and not per call."""
         pvals = tuple(np.int32(env[p]) for p in self.param_names)
+        run = spans.wrap(self.run, "repro.dispatch", n=env.get("n"))
         state: dict = {}
 
         def fn(tup):
@@ -526,7 +532,7 @@ class ParamCompiled:
                 tup = state["tup"]
             else:
                 state["seed"] = tup
-            out = self.run(tup, pvals)
+            out = run(tup, pvals)
             state["tup"] = out
             return out
 
@@ -558,7 +564,6 @@ def _build_param_compiled(lowered: ParamLowered, ntimes: int,
         return tuple(d[k] for k in names)
 
     avals, pavals = lowered.avals()
-    t0 = time.perf_counter()
     # donate the array operands: undonated calls copy the full
     # capacity-shaped buffers on every invocation, a cost proportional to
     # the ladder *capacity* rather than the rung being measured
@@ -578,11 +583,10 @@ def _build_param_compiled(lowered: ParamLowered, ntimes: int,
 
         exe = _compile_donated(fused, avals, pavals)
         run = exe
-    compile_seconds = time.perf_counter() - t0
     return ParamCompiled(
         lowered=lowered, names=names, run=run, executable=exe,
         ntimes=ntimes, sync_every_rep=sync_every_rep,
-        compile_seconds=compile_seconds,
+        compile_seconds=0.0,
     )
 
 
@@ -744,6 +748,61 @@ GLOBAL_CACHE = TranslationCache(capacity=_global_capacity())
 
 
 # ---------------------------------------------------------------------------
+# Spans around the cached stages
+# ---------------------------------------------------------------------------
+
+
+def _stamped(sp: spans.Span, build: Callable, field: str,
+             attrs: Callable[[], dict]) -> Callable:
+    """``build`` as a cache builder that ends ``sp`` when the artifact is
+    built and stores the span's seconds in its ``field``: before the
+    cache publishes it, so a concurrent waiter never copies it unset."""
+    def builder():
+        out = build()
+        sp.attrs.update(attrs())
+        sp.close()
+        setattr(out, field, sp.seconds)
+        return out
+    return builder
+
+
+def _lower_span(cache: "TranslationCache | None", key, build: Callable):
+    """Stage 1 through the cache inside a ``repro.lower`` span (``cache``
+    = hit/built); a built artifact's ``lower_seconds`` is the span's."""
+    with spans.span("repro.lower") as sp:
+        builder = _stamped(sp, build, "lower_seconds",
+                           lambda: {"cache": "built"})
+        if cache is None or key is None:
+            return builder()
+        out, hit = cache._lowered_get_or_build(key, builder)
+        if hit:
+            sp.attrs["cache"] = "hit"
+    if out.cache is None:
+        out.cache = cache
+    return out
+
+
+def _compile_span(cache: "TranslationCache | None", key, build: Callable):
+    """Stage 2 through the cache inside a ``repro.compile`` span:
+    ``source`` is memory (this cache), disk (jax's persistent cache, from
+    its hit counter; process-wide, so a concurrent compile's hit can be
+    credited here) or compiled. Returns ``(artifact, hit)``; a built
+    artifact's ``compile_seconds`` is the span's."""
+    with spans.span("repro.compile") as sp:
+        disk0 = disk_cache_stats()["hits"]
+        builder = _stamped(
+            sp, build, "compile_seconds",
+            lambda: {"source": "disk" if disk_cache_stats()["hits"] > disk0
+                     else "compiled"})
+        if cache is None or key is None:
+            return builder(), False
+        out, hit = cache._compiled_get_or_build(key, builder)
+        if hit:
+            sp.attrs["source"] = "memory"
+    return out, hit
+
+
+# ---------------------------------------------------------------------------
 # Stage 1 entry point
 # ---------------------------------------------------------------------------
 
@@ -782,7 +841,6 @@ def stage_lower(
         key = None  # unhashable pattern piece: bypass the cache
 
     def builder() -> Lowered:
-        t0 = time.perf_counter()
         plan = codegen.plan_nest(pattern, schedule, env)
         if backend == "jax":
             step = codegen.lower_jax(
@@ -798,16 +856,10 @@ def stage_lower(
         return Lowered(
             pattern=pattern, schedule=schedule, env=env, backend=backend,
             step=step, nest=plan.nest, key=key,
-            lower_seconds=time.perf_counter() - t0, cache=cache,
-            pallas_mode=pallas_mode,
+            lower_seconds=0.0, cache=cache, pallas_mode=pallas_mode,
         )
 
-    if cache is None or key is None:
-        return builder()
-    out, _hit = cache._lowered_get_or_build(key, builder)
-    if out.cache is None:
-        out.cache = cache
-    return out
+    return _lower_span(cache, key, builder)
 
 
 def stage_lower_parametric(
@@ -865,7 +917,6 @@ def stage_lower_parametric(
         key = None  # unhashable pattern piece: bypass the cache
 
     def builder() -> ParamLowered:
-        t0 = time.perf_counter()
         pnest = schedule.lower_symbolic(pattern.domain, params)
         kw = {} if chunk is None else {"chunk": chunk}
         if backend == "pallas":
@@ -881,18 +932,13 @@ def stage_lower_parametric(
         return ParamLowered(
             pattern=pattern, schedule=schedule, cap_env=cap_env,
             params=params, backend=backend, step=step, pnest=pnest,
-            key=key, lower_seconds=time.perf_counter() - t0,
+            key=key, lower_seconds=0.0,
             param_path=getattr(step, "param_path", "gather"),
             param_window_rank=getattr(step, "param_window_rank", 0),
             cache=cache, pallas_mode=pallas_mode,
         )
 
-    if cache is None or key is None:
-        return builder()
-    out, _hit = cache._lowered_get_or_build(key, builder)
-    if out.cache is None:
-        out.cache = cache
-    return out
+    return _lower_span(cache, key, builder)
 
 
 # ---------------------------------------------------------------------------
@@ -916,6 +962,7 @@ def precompile(thunks: Sequence[Callable[[], Any]],
         max_workers = min(4, len(thunks), os.cpu_count() or 1)
     if max_workers <= 1:
         return [t() for t in thunks]
+    thunks = [spans.carry(t) for t in thunks]
     with ThreadPoolExecutor(max_workers=max_workers) as ex:
         return list(ex.map(lambda t: t(), thunks))
 

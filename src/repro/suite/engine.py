@@ -84,6 +84,7 @@ from repro.core.errors import (
     SweepFailures,
     classify_failure,
 )
+from repro.core.spans import span
 
 from .axes import PlanPoint, SweepPlan
 from .journal import RunJournal
@@ -570,15 +571,14 @@ class SerialBackend(ExecutionBackend):
     def execute(self, units, strict):
         if not units:
             return []
-        t0 = time.perf_counter()
-        precompile([u.stage for u in units])
-        stage_intervals = [(t0, time.perf_counter())]
+        with span("repro.group.stage", groups=len(units)) as st:
+            precompile([u.stage for u in units])
         for u in units:
-            m0 = time.perf_counter()
-            u.run()
-            u.measure_interval = (m0, time.perf_counter())
+            with span("repro.group.measure") as m:
+                u.run()
+            u.measure_interval = (m.start, m.end)
             u.flush_journal()
-        return stage_intervals
+        return [(st.start, st.end)]
 
 
 class ThreadPoolBackend(ExecutionBackend):
@@ -617,24 +617,22 @@ class ThreadPoolBackend(ExecutionBackend):
         si_guard = threading.Lock()
 
         def work(u: _GroupRun) -> None:
-            s0 = time.perf_counter()
-            try:
-                u.stage()          # swallows faults unless strict
-            except Exception as e:
-                u.error = e
-                with si_guard:
-                    stage_intervals.append((s0, time.perf_counter()))
-                return
-            with si_guard:
-                stage_intervals.append((s0, time.perf_counter()))
-            with self._measure_lock(u.device_key):
-                m0 = time.perf_counter()
+            with span("repro.group.stage", groups=1) as st:
                 try:
-                    u.run()
+                    u.stage()          # swallows faults unless strict
                 except Exception as e:
                     u.error = e
-                finally:
-                    u.measure_interval = (m0, time.perf_counter())
+            with si_guard:
+                stage_intervals.append((st.start, st.end))
+            if u.error is not None:
+                return
+            with self._measure_lock(u.device_key):
+                with span("repro.group.measure") as m:
+                    try:
+                        u.run()
+                    except Exception as e:
+                        u.error = e
+                u.measure_interval = (m.start, m.end)
             if u.error is None:
                 try:
                     u.flush_journal()   # outside the measure lock
