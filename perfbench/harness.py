@@ -14,7 +14,8 @@ metric is a file of its own, found by the name ``BENCHMARK.json`` gives:
   metric's value, or None where it finds nothing to read.
 
 A run sets up (stage, inputs, one warm-up pass), measures passes over the
-ladder for ``seconds`` (``trace=1``: a shorter traced window instead),
+ladder for ``seconds``, dispatched a few seconds ahead of the pass it
+waits for (``trace=1``: a shorter traced window instead),
 reads the device's memory peak, checks what the timed calls produced
 against the reference, and returns the result line as a dict.
 """
@@ -23,12 +24,13 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import math
 import os
 import pathlib
 import sys
 import tempfile
 import time
-from collections import Counter
+from collections import Counter, deque
 from typing import Any, Callable
 
 from . import trace as tracing
@@ -38,6 +40,9 @@ HERE = "perfbench"
 # the traced window: a few seconds of steady passes, traced in a run of
 # its own, so the end-to-end runs carry no profiler cost
 TRACE_SECONDS = 3.0
+# the window dispatches this much work ahead of the pass it waits for,
+# so the device keeps working through a stall of the host
+LEAD_SECONDS = 4.0
 SPAN = "pb."  # prefix of the benchmark's own host spans in a trace
 # jax's own events for tracing, compiling and loading an executable
 _COMPILE_EVENTS = ("/jax/core/compile/", "/jax/compilation_cache/cache_")
@@ -171,12 +176,40 @@ class CompileCounter:
         jax.monitoring.unregister_event_duration_listener(self._on)
 
 
+_FIRST: list = []  # the jitted marker, made on first use
+
+
+def _marker(out):
+    """One element of a call's first output: a tiny program queued after
+    the call on its devices, which the host can wait on once the call's
+    own outputs are donated into the next call."""
+    import jax
+
+    if not _FIRST:
+        _FIRST.append(jax.jit(lambda x: x[(0,) * x.ndim]))
+    return _FIRST[0](jax.tree.leaves(out)[0])
+
+
+def lead_passes(pass_s: float) -> int:
+    """How many passes the window dispatches ahead of the one it waits
+    for: ``LEAD_SECONDS`` of work at the warm-up's pass time."""
+    if pass_s <= 0:
+        return 1
+    return max(1, math.ceil(LEAD_SECONDS / pass_s))
+
+
 def run_passes(calls: list[Call], reps: int, seconds: float,
-               spans: bool = False) -> Window:
-    """Passes over the ladder until ``seconds`` have gone by; the pass in
-    progress then completes. A pass makes ``reps`` calls per rung, rung
-    after rung, each ended by ``block_until_ready``. ``spans`` names the
-    host's part of each call in the profiler's trace."""
+               spans: bool = False, lead: int = 1) -> Window:
+    """Passes over the ladder until ``seconds`` have gone by. A pass makes
+    ``reps`` calls per rung, rung after rung, and ends in a marker of its
+    last call (``_marker``). Passes are dispatched up to ``lead`` ahead of
+    the one the host waits for, so that the device keeps working while
+    the host stands still; the host waits on the markers in order, and a
+    pass's time is the interval from the previous pass's completion to
+    its own, as the host sees them. When the time is up nothing more is
+    sent; the window closes when all that was sent has completed, and
+    counts all of it. ``spans`` names the host's part of each call and
+    wait in the profiler's trace."""
     import contextlib
 
     import jax
@@ -184,39 +217,65 @@ def run_passes(calls: list[Call], reps: int, seconds: float,
     span = (jax.profiler.TraceAnnotation if spans
             else lambda name: contextlib.nullcontext())
     passes: list[float] = []
-    nbytes: Counter = Counter()
-    t0 = time.perf_counter()
+    pending: deque = deque()
+    sent = 0
+    t0 = last = time.perf_counter()
     deadline = t0 + seconds
+
+    def wait_oldest():
+        nonlocal last
+        with span(SPAN + "wait"):
+            jax.block_until_ready(pending.popleft())
+        now = time.perf_counter()
+        passes.append(now - last)
+        last = now
+
     while True:
-        tp = time.perf_counter()
         with span(SPAN + "pass"):
             for c in calls:
                 for _ in range(reps):
                     with span(SPAN + "call:" + c.label):
                         out = c.run()
-                    with span(SPAN + "wait:" + c.label):
-                        jax.block_until_ready(out)
                     c.keep(out)
-        t1 = time.perf_counter()
-        passes.append(t1 - tp)
-        if t1 >= deadline:
+            pending.append(_marker(out))
+        sent += 1
+        while len(pending) > lead:
+            wait_oldest()
+        if time.perf_counter() >= deadline:
             break
+    while pending:
+        wait_oldest()
+    nbytes: Counter = Counter()
     for c in calls:
         for k, v in c.nbytes.items():
-            nbytes[k] += v * reps * len(passes)
-    return Window(seconds=t1 - t0, passes=passes,
-                  calls=len(passes) * reps * len(calls), nbytes=nbytes)
+            nbytes[k] += v * reps * sent
+    return Window(seconds=last - t0, passes=passes,
+                  calls=sent * reps * len(calls), nbytes=nbytes)
 
 
-def warm_up(calls: list[Call]) -> None:
-    """Every timed executable once, outside the window; nothing kept."""
+def warm_up(calls: list[Call], reps: int = 0) -> float:
+    """Every timed executable and the pass marker once, outside the
+    window; nothing kept. With ``reps``, then one whole pass, timed:
+    returns its seconds (0.0 without)."""
     import jax
 
+    out = None
     for c in calls:
-        jax.block_until_ready(c.run())
+        out = c.run()
+        jax.block_until_ready(out)
+    jax.block_until_ready(_marker(out))
+    if not reps:
+        return 0.0
+    t0 = time.perf_counter()
+    for c in calls:
+        for _ in range(reps):
+            out = c.run()
+    jax.block_until_ready(_marker(out))
+    return time.perf_counter() - t0
 
 
-def traced_passes(calls: list[Call], reps: int, seconds: float):
+def traced_passes(calls: list[Call], reps: int, seconds: float,
+                  lead: int = 1):
     """``run_passes`` under the profiler, into a temporary directory that
     is reduced and removed before this returns: (window, trace)."""
     import shutil
@@ -232,7 +291,7 @@ def traced_passes(calls: list[Call], reps: int, seconds: float):
         jax.profiler.start_trace(tmp, profiler_options=opts)
         t1 = time.perf_counter()
         try:
-            win = run_passes(calls, reps, seconds, spans=True)
+            win = run_passes(calls, reps, seconds, spans=True, lead=lead)
         finally:
             t2 = time.perf_counter()
             jax.profiler.stop_trace()
@@ -324,17 +383,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     cell = entry.build(cfg, ref, traffic, seed)
     t_warm = time.perf_counter()
     reps = int(traffic["reps"])
-    warm_up(cell.calls)
+    pass_s = warm_up(cell.calls, reps)
+    lead = lead_passes(pass_s)
     print(f"setup: imports and devices {t_build - t_start:.3f} s, build "
           f"{t_warm - t_build:.3f} s (stage {cell.stage_s:.3f} s), warm-up "
-          f"{time.perf_counter() - t_warm:.3f} s", file=sys.stderr)
+          f"{time.perf_counter() - t_warm:.3f} s (a pass {pass_s:.4f} s, "
+          f"{lead} dispatched ahead)", file=sys.stderr)
     with CompileCounter() as compiles:
         t_window = time.perf_counter()
         if trace:
             win, tr = traced_passes(cell.calls, reps,
-                                    min(seconds, TRACE_SECONDS))
+                                    min(seconds, TRACE_SECONDS), lead)
         else:
-            win, tr = run_passes(cell.calls, reps, seconds), None
+            win, tr = run_passes(cell.calls, reps, seconds, lead=lead), None
     dev["memory_peak_bytes"] = memory_peak(cell.devices)
 
     t_check = time.perf_counter()

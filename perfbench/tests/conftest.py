@@ -26,15 +26,10 @@ TINY = {"hbm_ladder": [8192, 16384, 24576],
 # list yet: a later change adds them with these entries and no other edit
 PENDING = {
     "configs": [
-        {"name": "jacobi2d_5pt", "source": "AdaptMemBench Jacobi-2D",
-         "file": "perfbench/configs/jacobi2d_5pt.json", "reduced": [],
-         "why": "stencil"},
         {"name": "allreduce_2x2", "source": "nccl-tests",
          "file": "perfbench/configs/allreduce_2x2.json", "reduced": ["b"],
          "why": "all-reduce"}],
     "workloads": [
-        {"name": "jacobi2d.hbm", "config": "jacobi2d_5pt",
-         "traffic": "hbm_grid", "chips": 1, "why": "stencil"},
         {"name": "triad.small", "config": "stream_triad",
          "traffic": "small_ladder", "chips": 1, "why": "dispatch"},
         {"name": "allreduce.4chip", "config": "allreduce_2x2",
@@ -49,7 +44,7 @@ PENDING = {
          "workloads": ["allreduce.4chip"]}],
 }
 # the cells each metric of the memory cells is reported in
-MEMORY_CELLS = ("jacobi2d.hbm", "triad.small")
+MEMORY_CELLS = ("triad.small",)
 
 
 def with_pending(bench: dict) -> dict:

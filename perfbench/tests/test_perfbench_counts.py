@@ -30,9 +30,25 @@ def test_triad_counts_twelve_bytes_per_point():
 
 def test_jacobi2d_counts_grid_read_and_interior_written():
     cfg, ref = _config("jacobi2d_5pt")
-    # a 4 x 4 grid: 16 points read, the 2 x 2 interior written, 4 B each
-    assert ref.traffic_bytes(cfg, 4) == {"hbm": 80}
-    assert ref.traffic_bytes(cfg, 16386)["hbm"] == 4 * (16386 ** 2 + 16384 ** 2)
+    # two 4 x 4 grids: 16 points read, the 2 x 2 interior written, 4 B each
+    assert ref.traffic_bytes(cfg, 4) == {"hbm": 2 * 80}
+    assert ref.traffic_bytes(cfg, 16386)["hbm"] == \
+        2 * 4 * (16386 ** 2 + 16384 ** 2)
+
+
+def test_jacobi2d_grid_reaches_hbm():
+    """Every grid of the committed traffic is at least 4x the largest
+    on-chip store (v5e's 128 MiB of VMEM), and the grid pairs one call
+    holds fit the chip's HBM."""
+    cfg, _ = _config("jacobi2d_5pt")
+    traffic = harness.load_json(REPO / "perfbench" / "traffic"
+                                / "hbm_grid.json")
+    programs = int(cfg["driver_config"]["programs"])
+    hbm = harness.load_peaks(REPO, "TPU v5 lite")["hbm_bytes"]
+    for n in traffic["n"]:
+        grid = 4 * n * n
+        assert grid >= 4 * 128 * 2**20, n
+        assert 2 * programs * grid <= hbm, n
 
 
 def test_allreduce_counts_ring_wire_bytes():
