@@ -167,6 +167,13 @@ def unified_program_schedule(
     return sch.tile_by_count(d0.name, programs, outer="prog", inner=d0.name)
 
 
+def _comparable(a) -> np.ndarray:
+    """An array numpy's comparisons accept: bfloat16 widened to float32
+    (exactly), everything else as it is."""
+    a = np.asarray(a)
+    return a.astype(np.float32) if a.dtype == jnp.bfloat16 else a
+
+
 # ---------------------------------------------------------------------------
 # Driver
 # ---------------------------------------------------------------------------
@@ -747,9 +754,11 @@ class Driver:
             got = {k: jnp.asarray(v) for k, v in arrays.items()}
             for _ in range(2):
                 got = lowered.step(got)
+        rtol, atol = pat.tolerance
         for k in want:
             np.testing.assert_allclose(
-                np.asarray(got[k]), want[k], rtol=1e-5, atol=1e-5,
+                _comparable(got[k]), _comparable(want[k]), rtol=rtol,
+                atol=atol,
                 err_msg=f"space {k} diverged under {sch.name}/{cfg.template}",
             )
         if vkey is not None:
@@ -790,8 +799,7 @@ class Driver:
                 e.context.setdefault(k, v)
             raise
         pts = pat.domain.point_count(env)
-        bpp = pat.bytes_per_point()
-        total_bytes = bpp * pts * cfg.ntimes
+        total_bytes = pat.sweep_bytes(env) * cfg.ntimes
         mix_extra: dict = {}
         if pat.mix is not None:
             # multi-pattern mixes: the statement accounts the primary
@@ -949,5 +957,6 @@ class Driver:
             spaces={s.name: s.concrete_shape(env) for s in pat.spaces},
             program_slices=slices,
             written=written,
-            itemsize=np.dtype(pat.space(written).dtype).itemsize,
+            itemsizes={s.name: np.dtype(s.dtype).itemsize
+                       for s in pat.spaces},
         )

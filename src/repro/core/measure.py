@@ -44,7 +44,8 @@ __all__ = [
     "latency_ns",
 ]
 
-# TPU v5e native tile for f32 operands: 8 sublanes x 128 lanes.
+# TPU v5e native tile for f32 operands: 8 sublanes x 128 lanes. Narrower
+# types pack more elements into the same 4 KiB ((16, 128) for bf16).
 NATIVE_TILE = (8, 128)
 NATIVE_TILE_BYTES = NATIVE_TILE[0] * NATIVE_TILE[1] * 4
 
@@ -267,21 +268,23 @@ def _touched_tiles(lo: int, hi: int, tile_elems: int) -> set[int]:
 def tile_traffic(
     *, spaces: Mapping[str, tuple[int, ...]],
     program_slices: Sequence[Mapping[str, tuple[int, int]]],
-    written: str, itemsize: int = 4,
+    written: str, itemsizes: Mapping[str, int] | None = None,
 ) -> TileTraffic:
     """Tile traffic for 1D-per-program slices (the paper's SMP studies).
 
     ``program_slices[p][space] = (lo, hi)`` is program p's contiguous
     element range in the *flattened* space. Tiles are NATIVE_TILE_BYTES
     blocks of the flat layout — the exact analogue of 64B cache lines.
+    ``itemsizes[space]`` is the space's element size in bytes (4 where
+    not given), so a bf16 tile holds twice the elements of an f32 one.
     """
-    tile_elems = NATIVE_TILE_BYTES // itemsize
     fetches = 0
     writebacks = 0
     writers: dict[tuple[str, int], int] = {}
     for sl in program_slices:
         for space, (lo, hi) in sl.items():
-            tiles = _touched_tiles(lo, hi, tile_elems)
+            size = (itemsizes or {}).get(space, 4)
+            tiles = _touched_tiles(lo, hi, NATIVE_TILE_BYTES // size)
             fetches += len(tiles)
             if space == written:
                 writebacks += len(tiles)

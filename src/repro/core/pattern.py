@@ -50,12 +50,16 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class DataSpace:
-    """One allocated array. ``shape`` entries are params or ints (affine ok)."""
+    """One allocated array. ``shape`` entries are params or ints (affine ok).
+
+    ``init`` is a scalar, an :class:`Affine` in the params (a constant
+    fixed by the env, such as a decode position ``n - 1``), or
+    ``f(*index_grids)``."""
 
     name: str
     shape: tuple[Affine | int | str, ...]
     dtype: str = "float32"
-    init: float | Callable[..., np.ndarray] = 0.0  # scalar or f(*index_grids)
+    init: float | Affine | Callable[..., np.ndarray] = 0.0
 
     def concrete_shape(self, env: Mapping[str, int]) -> tuple[int, ...]:
         return tuple(Affine.of(s).eval(env) for s in self.shape)
@@ -120,6 +124,19 @@ class PatternSpec:
     # components' bytes (the statement accounts the primary only) and
     # stamp the split into ``extra["mix"]``.
     mix: Mapping[str, object] | None = None
+    # Custom kernels only. ``writes`` names the spaces the kernel
+    # writes: the executable donates and returns those alone, and takes
+    # the rest (weights, positions) as read-only operands. None: every
+    # space is donated and threaded, as for the affine patterns.
+    writes: tuple[str, ...] | None = None
+    # ``traffic(env) -> bytes``: the compulsory bytes of one sweep where
+    # no bytes-per-point count applies (a contraction over a cache);
+    # None: the statement's bytes per point times the domain's points.
+    traffic: Callable[[Mapping[str, int]], int] | None = None
+    # ``(rtol, atol)`` of the oracle check in ``Driver.validate``: exact
+    # up to float32 rounding by default; a pattern that stores bfloat16
+    # states the rounding it is held to.
+    tolerance: tuple[float, float] = (1e-5, 1e-5)
 
     def space(self, name: str) -> DataSpace:
         for s in self.spaces:
@@ -137,12 +154,21 @@ class PatternSpec:
             total += _np.dtype(self.space(acc.space).dtype).itemsize
         return total
 
+    def sweep_bytes(self, env: Mapping[str, int]) -> int:
+        """Compulsory bytes one sweep moves at ``env``: ``traffic`` where
+        the pattern gives it, else bytes per point times points."""
+        if self.traffic is not None:
+            return int(self.traffic(env))
+        return self.bytes_per_point() * self.domain.point_count(env)
+
     def allocate(self, env: Mapping[str, int]) -> dict[str, np.ndarray]:
         """Materialize + initialize all data spaces (the init schedule)."""
         out = {}
         for s in self.spaces:
             shape = s.concrete_shape(env)
-            if callable(s.init):
+            if isinstance(s.init, Affine):
+                out[s.name] = np.full(shape, s.init.eval(env), dtype=s.dtype)
+            elif callable(s.init):
                 grids = np.meshgrid(
                     *[np.arange(n, dtype=np.int64) for n in shape], indexing="ij"
                 ) if shape else []

@@ -22,6 +22,15 @@ Whether a span records is decided when it is entered. Callables bound
 for a hot loop (``wrap``) decide once, when they are bound: spans must be
 on before ``bind()`` for its dispatch spans to appear. Program span
 names start with ``repro.`` and are stable.
+
+Device scopes. Code inside an executable is named with
+``jax.named_scope("repro....")``; the name rides in each HLO
+instruction's ``op_name`` metadata, which a device trace's op events do
+not carry. Staging notes every executable it compiles or loads
+(``note_executable``, a weak reference and nothing more), and
+``device_scopes()`` reads their text on demand: per module, each
+instruction's innermost ``repro.*`` scope, the map a trace reader needs
+to put each device op under its scope.
 """
 from __future__ import annotations
 
@@ -29,8 +38,10 @@ import contextlib
 import contextvars
 import functools
 import itertools
+import re
 import threading
 import time
+import weakref
 from typing import Callable
 
 import jax
@@ -181,3 +192,60 @@ def recording():
     finally:
         got.extend(drain())
         _on = was
+
+
+# ---------------------------------------------------------------------------
+# device scopes: HLO instruction -> repro.* scope, per executable module
+# ---------------------------------------------------------------------------
+
+# [weak reference to an executable, its parsed (module, map) or None]
+_executables: list[list] = []
+_MODULE = re.compile(r"^HloModule (\S+?),? ")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?(\S+) = .*?op_name=\"([^\"]*)\"")
+
+
+def note_executable(executable) -> None:
+    """Keep a weak reference to a compiled executable for
+    ``device_scopes``; costs nothing until that is called."""
+    with _lock:
+        _executables[:] = [e for e in _executables if e[0]() is not None]
+        try:
+            _executables.append([weakref.ref(executable), None])
+        except TypeError:  # an object that cannot be weakly referenced
+            pass
+
+
+def scope_map(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """``(module name, {instruction: scope})`` from an executable's HLO
+    text: the scope is the innermost ``repro.*`` component of the
+    instruction's ``op_name``; instructions under none are left out."""
+    module, out = "", {}
+    for line in hlo_text.splitlines():
+        if not module:
+            m = _MODULE.match(line)
+            if m:
+                module = m.group(1)
+                continue
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        scopes = [c for c in m.group(2).split("/") if c.startswith("repro.")]
+        if scopes:
+            out[m.group(1)] = scopes[-1]
+    return module, out
+
+
+def device_scopes() -> dict[str, dict[str, str]]:
+    """Per module name, each instruction's ``repro.*`` scope, over every
+    live executable noted so far; modules of one name merge."""
+    with _lock:
+        entries = [(e, e[0]()) for e in _executables]
+    out: dict[str, dict[str, str]] = {}
+    for entry, exe in entries:
+        if exe is None:
+            continue
+        if entry[1] is None:
+            entry[1] = scope_map(exe.as_text())
+        module, scopes = entry[1]
+        out.setdefault(module, {}).update(scopes)
+    return out

@@ -34,7 +34,9 @@ Donation invariant: every *measurement* executable — ``ParamCompiled``
 always, ``Compiled`` when built with ``donate=True`` (what
 ``Driver.prepare`` requests) — donates its array operands, so a call
 consumes its input tuple instead of paying a buffer copy; the ``bind``
-methods thread outputs into subsequent calls. Donated executables hit
+methods thread outputs into subsequent calls. A custom kernel that names
+the spaces it writes (``PatternSpec.writes``) donates those alone: its
+weights and positions are read-only operands, passed through unchanged. Donated executables hit
 jax's persistent compile cache across processes like undonated ones.
 """
 from __future__ import annotations
@@ -153,6 +155,7 @@ def fingerprint_pattern(pattern: PatternSpec) -> tuple:
         _freeze(pattern.derived),
         _freeze(pattern.trace),
         _freeze(pattern.mix),
+        pattern.writes,
     )
 
 
@@ -360,31 +363,60 @@ class Compiled:
 
 def _build_compiled(lowered: Lowered, ntimes: int,
                     sync_every_rep: bool, donate: bool = False) -> Compiled:
+    """The executable of ``ntimes`` sweeps. It takes the spaces the
+    pattern writes (``PatternSpec.writes``; every space where None),
+    donated when ``donate`` and returned, then any read-only ones
+    (weights, positions), neither donated nor returned. ``run`` keeps
+    the ``run(tup) -> tup`` contract over every space: the read-only
+    arrays come back as they went in."""
     names = lowered.space_names
     step = lowered.step
+    writes = lowered.pattern.writes
+    own = names if writes is None else tuple(k for k in names if k in writes)
+    rest = tuple(k for k in names if k not in own)
 
-    def step_t(tup):
-        d = dict(zip(names, tup))
-        d = step(d)
-        return tuple(d[k] for k in names)
+    def step_t(tup, ro=()):
+        d = dict(zip(own, tup))
+        d.update(zip(rest, ro))
+        out = step(d)
+        for k in rest:
+            if out[k] is not d[k]:
+                raise ValueError(
+                    f"pattern {lowered.pattern.name!r} writes space {k!r}, "
+                    "which its `writes` does not name")
+        return tuple(out[k] for k in own)
 
-    avals = lowered.avals()
+    by_name = dict(zip(names, lowered.avals()))
+    avals = (tuple(by_name[k] for k in own),)
+    if rest:
+        avals += (tuple(by_name[k] for k in rest),)
     compile_one = (_compile_donated if donate
                    else lambda fn, *a: jax.jit(fn).lower(*a).compile())
     if sync_every_rep:
-        exe = compile_one(step_t, avals)
+        exe = compile_one(step_t, *avals)
 
-        def run(tup):
+        def run_own(tup, *ro):
             for _ in range(ntimes):
-                tup = exe(tup)
+                tup = exe(tup, *ro)
                 jax.block_until_ready(tup)
             return tup
     else:
-        def fused(tup):
-            return jax.lax.fori_loop(0, ntimes, lambda _, t: step_t(t), tup)
+        def fused(tup, *ro):
+            return jax.lax.fori_loop(0, ntimes, lambda _, t: step_t(t, *ro),
+                                     tup)
 
-        exe = compile_one(fused, avals)
-        run = exe
+        exe = compile_one(fused, *avals)
+        run_own = exe
+    spans.note_executable(exe)
+    if rest:
+        at = {k: i for i, k in enumerate(names)}
+
+        def run(tup):
+            got = dict(zip(own, run_own(tuple(tup[at[k]] for k in own),
+                                        tuple(tup[at[k]] for k in rest))))
+            return tuple(got.get(k, tup[at[k]]) for k in names)
+    else:
+        run = run_own
     return Compiled(
         lowered=lowered, names=names, run=run, executable=exe,
         ntimes=ntimes, sync_every_rep=sync_every_rep,
@@ -583,6 +615,7 @@ def _build_param_compiled(lowered: ParamLowered, ntimes: int,
 
         exe = _compile_donated(fused, avals, pavals)
         run = exe
+    spans.note_executable(exe)
     return ParamCompiled(
         lowered=lowered, names=names, run=run, executable=exe,
         ntimes=ntimes, sync_every_rep=sync_every_rep,

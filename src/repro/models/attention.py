@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .layers import DEFAULT_DTYPE, init_linear, rope
+from .layers import DEFAULT_DTYPE, init_linear, rms_norm, rope
 
 __all__ = [
     "gqa_init",
@@ -34,6 +34,8 @@ __all__ = [
 ]
 
 NEG_INF = -1e30
+# DeepSeek-V2's rms_norm_eps, used by the kv_a_layernorm over c_kv
+KV_NORM_EPS = 1e-6
 
 
 # ---------------------------------------------------------------------------
@@ -278,6 +280,8 @@ def mla_init(key, d: int, n_heads: int, mla, *, dtype=DEFAULT_DTYPE) -> dict:
         "w_uv": init_linear(ks[2], mla.kv_lora_rank,
                             n_heads * mla.v_head_dim, dtype=dtype),
         "w_o": init_linear(ks[3], n_heads * mla.v_head_dim, d, dtype=dtype),
+        # kv_a_layernorm: RMSNorm over the latent before it is cached
+        "kv_norm": jnp.zeros((mla.kv_lora_rank,), jnp.float32),
     }
     if mla.q_lora_rank:
         p["w_dq"] = init_linear(ks[4], d, mla.q_lora_rank, dtype=dtype)
@@ -294,39 +298,48 @@ def mla_apply(
     kv_chunk: int = 1024,
     absorbed_decode: bool = True,
 ) -> tuple[jnp.ndarray, dict | None]:
-    """MLA attention. Cache stores compressed c_kv + shared rope key only."""
+    """MLA attention. Cache stores compressed c_kv + shared rope key only.
+
+    The decode step's parts run in named scopes that the device trace
+    reads back: ``repro.mla.project`` (projections and the absorption),
+    ``repro.mla.append`` (the cache row writes) and ``repro.mla.scan``
+    (scores, online softmax and the combine over the cache)."""
     B, Sq, d = x.shape
     H = n_heads
     dn, dr, dv, c = (mla.qk_nope_head_dim, mla.qk_rope_head_dim,
                      mla.v_head_dim, mla.kv_lora_rank)
 
-    # --- queries
-    if mla.q_lora_rank:
-        q = (x @ p["w_dq"]) @ p["w_uq"]
-    else:
-        q = x @ p["w_q"]
-    q = q.reshape(B, Sq, H, dn + dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = rope(q_rope, positions, theta=rope_theta)
+    with jax.named_scope("repro.mla.project"):
+        # --- queries
+        if mla.q_lora_rank:
+            q = (x @ p["w_dq"]) @ p["w_uq"]
+        else:
+            q = x @ p["w_q"]
+        q = q.reshape(B, Sq, H, dn + dr)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+        q_rope = rope(q_rope, positions, theta=rope_theta)
 
-    # --- compressed kv for the new tokens
-    dkv = x @ p["w_dkv"]                          # (B,Sq,c+dr)
-    ckv_new, krope_new = dkv[..., :c], dkv[..., c:]
-    krope_new = rope(krope_new[..., None, :], positions,
-                     theta=rope_theta)[..., 0, :]
+        # --- compressed kv for the new tokens
+        dkv = x @ p["w_dkv"]                          # (B,Sq,c+dr)
+        ckv_new = rms_norm(dkv[..., :c], p["kv_norm"], eps=KV_NORM_EPS)
+        krope_new = rope(dkv[..., None, c:], positions,
+                         theta=rope_theta)[..., 0, :]
 
     if cache is not None:
         start = cache["len"]
-        ckv = jax.lax.dynamic_update_slice(
-            cache["ckv"], ckv_new.astype(cache["ckv"].dtype), (0, start, 0))
-        krope = jax.lax.dynamic_update_slice(
-            cache["krope"], krope_new.astype(cache["krope"].dtype),
-            (0, start, 0))
+        with jax.named_scope("repro.mla.append"):
+            ckv = jax.lax.dynamic_update_slice(
+                cache["ckv"], ckv_new.astype(cache["ckv"].dtype),
+                (0, start, 0))
+            krope = jax.lax.dynamic_update_slice(
+                cache["krope"], krope_new.astype(cache["krope"].dtype),
+                (0, start, 0))
         new_cache = {"ckv": ckv, "krope": krope, "len": start + Sq}
         if absorbed_decode:
             out = _mla_absorbed(p, q_nope, q_rope, ckv, krope, H=H, mla=mla,
                                 q_offset=start, kv_chunk=kv_chunk)
-            return out.reshape(B, Sq, H * dv) @ p["w_o"], new_cache
+            with jax.named_scope("repro.mla.project"):
+                return out.reshape(B, Sq, H * dv) @ p["w_o"], new_cache
         ckv_all, krope_all, q_off = ckv, krope, start
     else:
         new_cache = None
@@ -359,21 +372,19 @@ def _mla_absorbed(p, q_nope, q_rope, ckv, krope, *, H, mla, q_offset, kv_chunk):
     c, dr, dv = mla.kv_lora_rank, mla.qk_rope_head_dim, mla.v_head_dim
     Sk = ckv.shape[1]
     scale = np.float32(1.0 / np.sqrt(dn + dr))
-    w_uk = p["w_uk"].reshape(c, H, dn)
-    q_c = jnp.einsum("bqhd,chd->bqhc", q_nope.astype(jnp.float32),
-                     w_uk.astype(jnp.float32)) * scale
-    q_r = q_rope.astype(jnp.float32) * scale
+    with jax.named_scope("repro.mla.project"):
+        w_uk = p["w_uk"].reshape(c, H, dn)
+        q_c = jnp.einsum("bqhd,chd->bqhc", q_nope.astype(jnp.float32),
+                         w_uk.astype(jnp.float32)) * scale
+        q_r = q_rope.astype(jnp.float32) * scale
+        q_cb = q_c.astype(ckv.dtype)
+        q_rb = q_r.astype(krope.dtype)
 
     kv_chunk = min(kv_chunk, Sk)
     while Sk % kv_chunk:
         kv_chunk -= 1
     n_chunks = Sk // kv_chunk
-    cc = jnp.moveaxis(ckv.reshape(B, n_chunks, kv_chunk, c), 1, 0)
-    rc = jnp.moveaxis(krope.reshape(B, n_chunks, kv_chunk, dr), 1, 0)
     q_pos = jnp.arange(Sq) + q_offset
-
-    q_cb = q_c.astype(ckv.dtype)
-    q_rb = q_r.astype(krope.dtype)
 
     def body(carry, inp):
         m, l, acc = carry
@@ -394,12 +405,16 @@ def _mla_absorbed(p, q_nope, q_rope, ckv, krope, *, H, mla, q_offset, kv_chunk):
             preferred_element_type=jnp.float32)
         return (m_new, l_new, acc_new), None
 
-    m0 = jnp.full((B, Sq, H), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, Sq, H), jnp.float32)
-    a0 = jnp.zeros((B, Sq, H, c), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
-                                  (cc, rc, jnp.arange(n_chunks)))
-    out_c = acc / jnp.maximum(l, 1e-30)[..., None]
-    w_uv = p["w_uv"].reshape(c, H, dv)
-    return jnp.einsum("bqhc,chd->bqhd", out_c,
-                      w_uv.astype(jnp.float32)).astype(ckv.dtype)
+    with jax.named_scope("repro.mla.scan"):
+        cc = jnp.moveaxis(ckv.reshape(B, n_chunks, kv_chunk, c), 1, 0)
+        rc = jnp.moveaxis(krope.reshape(B, n_chunks, kv_chunk, dr), 1, 0)
+        m0 = jnp.full((B, Sq, H), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((B, Sq, H), jnp.float32)
+        a0 = jnp.zeros((B, Sq, H, c), jnp.float32)
+        (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0),
+                                      (cc, rc, jnp.arange(n_chunks)))
+        out_c = acc / jnp.maximum(l, 1e-30)[..., None]
+    with jax.named_scope("repro.mla.project"):
+        w_uv = p["w_uv"].reshape(c, H, dv)
+        return jnp.einsum("bqhc,chd->bqhd", out_c,
+                          w_uv.astype(jnp.float32)).astype(ckv.dtype)

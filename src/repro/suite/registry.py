@@ -48,8 +48,10 @@ def all_tags() -> tuple[str, ...]:
 def load_builtins() -> None:
     """Import the built-in declarative entries (idempotent)."""
     from . import catalog as _builtin  # noqa: F401
+    from . import decode as _decode
     from . import derived as _derived
     from . import spatter_io as _spatter
 
     _derived.register_derived()
+    _decode.register_decode()
     _spatter.register_trace()

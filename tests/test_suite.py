@@ -181,6 +181,7 @@ _EXPECTED_PATHS = {
     "derived_moe_dispatch": {None: "specialized"},  # custom kernel
     "derived_lm_embed": {None: "specialized"},     # custom kernel
     "derived_train_update": {None: "strided"},     # independent template
+    "derived_mla_decode": {None: "specialized"},   # custom kernel
     "spatter_ms1": {"ms1": "specialized",          # bound-index kernel
                     "uniform": "gather"},          # affine trace, programs=4
 }
@@ -188,7 +189,7 @@ _EXPECTED_PATHS = {
 # parametric=True must raise for these (custom kernel with no
 # variant-level parametric pin)
 _TRUE_RAISES = {"pointer_chase", "derived_moe_dispatch", "derived_lm_embed",
-                "spatter_ms1", "mess_contended"}
+                "spatter_ms1", "mess_contended", "derived_mla_decode"}
 
 # Window dimensionality the strided regime must resolve per (workload,
 # variant): 1-D nests window the lane band alone; the stencil nests

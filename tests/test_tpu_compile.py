@@ -107,3 +107,38 @@ def test_over_vmem_triad_refused_before_mosaic():
     assert ctx["reason"] == "vmem" and ctx["backend"] == "pallas"
     assert ctx["vmem_bytes"] == 3 * (1 << 24) * np.dtype(np.float32).itemsize
     assert str(ctx["vmem_bytes"]) in str(ei.value)
+
+
+def test_mla_decode_step_compiles_at_published_widths(one_chip):
+    """One layer of DeepSeek-V2-Lite's MLA decode step at mla.decode32k's
+    batch and context: the written spaces alone donated, the cache
+    aliased in place, and the three ``repro.mla.*`` scopes in the
+    executable the device trace is read against."""
+    from repro.core import spans
+    from repro.suite.decode import mla_decode
+
+    env = {"n": 32768}
+    pat = mla_decode("deepseek-v2-lite-16b", 1, 24)
+    step = pat.kernel(pat, env)
+    names = sorted(s.name for s in pat.spaces)
+    own = [k for k in names if k in pat.writes]
+    rest = [k for k in names if k not in own]
+
+    def sds(k):
+        s = pat.space(k)
+        return _sds(s.concrete_shape(env), one_chip, jnp.dtype(s.dtype))
+
+    def fused(tup, ro):
+        d = dict(zip(own, tup))
+        d.update(zip(rest, ro))
+        out = step(d)
+        return tuple(out[k] for k in own)
+
+    compiled = jax.jit(fused, donate_argnums=(0,)).lower(
+        tuple(sds(k) for k in own), tuple(sds(k) for k in rest)).compile()
+    cache = sum(int(np.prod(pat.space(k).concrete_shape(env))) * 2
+                for k in ("ckv", "krope"))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache
+    _, scopes = spans.scope_map(compiled.as_text())
+    assert {"repro.mla.project", "repro.mla.append",
+            "repro.mla.scan"} <= set(scopes.values())
